@@ -1275,6 +1275,8 @@ if __name__ == "__main__":
                          "and METRICS_stream.prom (Prometheus text "
                          "exposition), prints the SLO-miss attribution table")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     fn = {"paged": run_paged, "migrate": run_migrate,
           "pipeline": run, "directory": run_directory,
           "stream": run_stream, "transport": run_transport,

@@ -23,9 +23,6 @@ class PerfConfig:
     accum_dtype: str = "bfloat16"  # grad accumulator dtype (bfloat16 | float32)
     # sharding strategy (distributed/sharding.py rule-table variants)
     partitioning: str = "tp"       # tp | zero3 (layer-stack params over data)
-    # kernels (real-TPU path; dry-run keeps XLA ref so cost_analysis sees flops)
-    use_pallas: bool = False
-    pallas_interpret: bool = True  # CPU validation; False on real TPU
     # kv cache dtype for decode shapes ("bfloat16" | "int8")
     kv_dtype: str = "bfloat16"
     # unroll the decode layer loop: a lax.scan DUS-updates the stacked KV

@@ -212,8 +212,10 @@ class EndpointRegistry:
             idle_ticks_to_zero=spec.idle_ticks_to_zero,
             control_every_steps=spec.control_every_steps,
             transport=self.transport)
+        # every endpoint's replicas share the devices: place across all
         orch = Orchestrator(spec.engine_factory(), cfg,
-                            tracer=self.tracer, metrics=self.metrics)
+                            tracer=self.tracer, metrics=self.metrics,
+                            device_peers=lambda: self._other_engines(spec.name))
         # autoscaler scale-ups go through the cluster budget (and may
         # trigger a priority eviction) exactly like first-request wakeups
         orch.replica_gate = lambda name=spec.name: self._admit_replica(
@@ -250,6 +252,11 @@ class EndpointRegistry:
                 "priority": ep.spec.priority}
 
     # ----------------------------------------------------------- capacity
+    def _other_engines(self, name: str):
+        for n, ep in self._eps.items():
+            if n != name:
+                yield from ep.orch.engines
+
     def total_replicas(self) -> int:
         return sum(len(ep.orch.engines) for ep in self._eps.values())
 

@@ -15,9 +15,13 @@ this module is the *real-engine* backend used by examples and tests.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import itertools
 import time
-from typing import Callable
+from typing import Callable, Iterable
+
+import jax
 
 from repro.core.autoscaler import Autoscaler, HPAConfig
 from repro.core.cache_directory import ClusterCacheDirectory
@@ -93,7 +97,9 @@ class Orchestrator:
     def __init__(self, make_engine: Callable[[], InferenceEngine],
                  cfg: OrchestratorConfig = OrchestratorConfig(),
                  tracer: Tracer | None = None,
-                 metrics: MetricsRegistry | None = None):
+                 metrics: MetricsRegistry | None = None,
+                 device_peers: Callable[[], Iterable[InferenceEngine]]
+                 | None = None):
         self.cfg = cfg
         self.make_engine = make_engine
         self._next_lb_id = 0
@@ -135,9 +141,13 @@ class Orchestrator:
         # return vetoes it (the EndpointRegistry enforces the cluster-wide
         # replica budget and priority eviction through this)
         self.replica_gate: Callable[[], bool] | None = None
+        # other endpoints' replicas sharing this process's devices (the
+        # registry passes them); placement counts them with our own
+        self.device_peers = device_peers or (lambda: ())
         self._idle_ticks = 0
-        self.engines: list[InferenceEngine] = [self._spawn()
-                                               for _ in range(cfg.min_replicas)]
+        self.engines: list[InferenceEngine] = []
+        for _ in range(cfg.min_replicas):
+            self.engines.append(self._spawn())
         self._cold: dict[int, int] = {}
         self.profiler = Profiler(registry=self.metrics)
         # proactive goodput policy: a per-endpoint planner whose horizon
@@ -175,12 +185,21 @@ class Orchestrator:
         # (serving/api.py, benches) take them via drain_events().
         self.events: list = []
 
+    def _place(self) -> jax.Device:
+        """The device for a new replica: the first one holding no replica,
+        shared only once every device holds one (fewest replicas first)."""
+        load = collections.Counter(
+            getattr(e, "device", None)
+            for e in itertools.chain(self.engines, self.device_peers()))
+        return min(jax.devices(), key=lambda d: load[d])
+
     def _spawn(self) -> InferenceEngine:
-        """Create a replica with a stable monotonic identity: prefix-affinity
-        rendezvous hashing and the cache directory key on it, so routing is
-        reproducible and membership churn remaps only the departed replica's
-        keys."""
-        eng = self.make_engine()
+        """Create a replica on its own device (:meth:`_place`) with a stable
+        monotonic identity: prefix-affinity rendezvous hashing and the cache
+        directory key on it, so routing is reproducible and membership churn
+        remaps only the departed replica's keys."""
+        with jax.default_device(self._place()):
+            eng = self.make_engine()
         eng.lb_id = self._next_lb_id
         self._next_lb_id += 1
         # label hygiene on shared registries: two endpoints both have an

@@ -79,11 +79,11 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float | None = None, bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q: (B,H,Sq,d); k,v: (B,KV,Skv,d) -> (B,H,Sq,d).
 
     Sq/Skv must be multiples of bq/bk (ops.py pads).  ``interpret=True`` runs
-    the kernel body on CPU for validation; on TPU pass False.
+    the kernel body on CPU for validation.
     """
     B, H, Sq, d = q.shape
     KV, Skv = k.shape[1], k.shape[2]

@@ -7,7 +7,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
 
 
 def _pad_to(x, axis, mult):
@@ -20,11 +19,10 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths), pad
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "use_pallas",
-                                             "bq", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
+                                             "interpret"))
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
-              use_pallas: bool = True, bq: int = 128, bk: int = 128,
-              interpret: bool = True):
+              bq: int = 128, bk: int = 128, interpret: bool = False):
     """Model layout: q (B,Sq,H,d); k,v (B,Skv,KV,d) -> (B,Sq,H,d).
 
     Pads Sq/Skv to block multiples; pad keys are masked out by the causal
@@ -34,9 +32,6 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    if not use_pallas:
-        out = attention_ref(qt, kt, vt, causal=causal, window=window)
-        return out.transpose(0, 2, 1, 3)
     qt, pq = _pad_to(qt, 2, bq)
     kt, pk = _pad_to(kt, 2, bk)
     vt, _ = _pad_to(vt, 2, bk)

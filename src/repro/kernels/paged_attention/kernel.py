@@ -65,7 +65,7 @@ def _kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_attention(q, k_pages, v_pages, block_table, context_len, *,
-                    scale: float | None = None, interpret: bool = True):
+                    scale: float | None = None, interpret: bool = False):
     """q (B,H,d); pools (num_blocks, bs, KV, d); block_table (B, max_blk);
     context_len (B,) -> (B,H,d)."""
     B, H, d = q.shape

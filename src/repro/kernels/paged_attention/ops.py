@@ -6,13 +6,10 @@ import functools
 import jax
 
 from repro.kernels.paged_attention.kernel import paged_attention
-from repro.kernels.paged_attention.ref import paged_attention_ref
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_pages, v_pages, block_table, context_len, *,
-                           use_pallas: bool = True, interpret: bool = True):
-    if not use_pallas:
-        return paged_attention_ref(q, k_pages, v_pages, block_table, context_len)
+                           interpret: bool = False):
     return paged_attention(q, k_pages, v_pages, block_table, context_len,
                            interpret=interpret)

@@ -6,12 +6,9 @@ import functools
 import jax
 
 from repro.kernels.ssd_scan.kernel import ssd_scan
-from repro.kernels.ssd_scan.ref import ssd_scan_ref
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_chunked_scan(x, B, C, dt, da, *, chunk: int = 128,
-                     use_pallas: bool = True, interpret: bool = True):
-    if not use_pallas:
-        return ssd_scan_ref(x, B, C, dt, da, chunk=chunk)
+                     interpret: bool = False):
     return ssd_scan(x, B, C, dt, da, chunk=chunk, interpret=interpret)

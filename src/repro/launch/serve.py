@@ -1,8 +1,11 @@
-"""Serving launcher: cloud-native orchestrated engines (reduced, CPU) or
+"""Serving launcher: cloud-native orchestrated engines, or the
 production-mesh serve-step dry-run.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --requests 12
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --stream
+``--arch`` is taken as given: a published config (``qwen2-0.5b``) serves at
+its full widths, a ``-smoke`` one at reduced widths (the CPU demo):
+
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b-smoke --requests 12
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b-smoke --stream
     PYTHONPATH=src python -m repro.launch.serve --arch gemma3-27b --dryrun
 
 ``--stream`` serves through the OpenAI-style completions front-end
@@ -138,7 +141,9 @@ def main(argv=None):
                        (["--perf"] + args.perf if args.perf else []))
 
     from repro.configs import get_config
-    cfg = get_config(args.arch + "-smoke")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    cfg = get_config(args.arch)
     registry = _build_registry(args, cfg)
     _print_models(registry)
     rc = _serve_stream(args, cfg, registry) if args.stream \

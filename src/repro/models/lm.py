@@ -19,6 +19,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.configs.base import ModelConfig
 from repro.configs.perf import BASELINE, PerfConfig
 from repro.models import layers as L
@@ -104,6 +105,8 @@ class LM:
                prefix_len, max_len, shd, true_len=None, block_table=None,
                live=None):
         cfg, perf = self.cfg, self.perf
+        # kernels serve inference only: pallas_call has no reverse-mode rule
+        kmode = "ref" if mode == "train" else kernels.kernel_mode()
         h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         new_cache = None
         if kind == "ssm":
@@ -116,7 +119,7 @@ class LM:
                 mix, new_cache = M.ssd_apply_full(
                     p["mixer"], h, cfg, shd, want_state=(mode == "prefill"),
                     true_len=true_len if mode == "prefill" else None,
-                    use_pallas=perf.use_pallas, interpret=perf.pallas_interpret)
+                    kernel=kmode)
         else:
             window = cfg.window_for(kind)
             theta = cfg.rope_theta_local if kind == "attn_local" else cfg.rope_theta
@@ -138,14 +141,13 @@ class LM:
                 from repro.serving.kv_cache import paged_gather, paged_write
                 kp, vp = paged_write(cache["k"], cache["v"], block_table, pos,
                                      k[:, 0], v[:, 0], live=live)
-                if perf.use_pallas:
+                if kmode != "ref":
                     ctx_len = pos + 1
                     if live is not None:
                         ctx_len = jnp.where(live, ctx_len, 0)
                     ctx = paged_decode_attention(
                         q[:, 0], kp, vp, block_table, ctx_len,
-                        use_pallas=True,
-                        interpret=perf.pallas_interpret)[:, None]
+                        interpret=kmode == "interpret")[:, None]
                 else:
                     S_ctx = block_table.shape[1] * cache["k"].shape[1]
                     gk = paged_gather(kp, block_table, S_ctx)
@@ -178,12 +180,11 @@ class LM:
                 new_cache = L.cache_write_chunk(cache, k, v, pos, true_len,
                                                 ring=window > 0)
             else:
-                if perf.use_pallas and prefix_len == 0:
+                if kmode != "ref" and prefix_len == 0:
                     from repro.kernels.flash_attention.ops import attention as FA
                     ctx = FA(q, k, v, causal=True, window=window,
-                             use_pallas=True, bq=min(128, q.shape[1]),
-                             bk=min(128, k.shape[1]),
-                             interpret=perf.pallas_interpret)
+                             bq=min(128, q.shape[1]), bk=min(128, k.shape[1]),
+                             interpret=kmode == "interpret")
                 else:
                     ctx = L.attention_full(
                         q, k, v, causal=True, window=window, prefix_len=prefix_len,

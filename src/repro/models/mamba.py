@@ -133,7 +133,7 @@ def _ssd_scan_chunks(xc, Bc, Cc, da, dt, h0, H: int, Q: int):
 
 
 def ssd_apply_full(p, x, cfg: ModelConfig, shd=_noop_shd, *, want_state: bool = False,
-                   true_len=None, use_pallas: bool = False, interpret: bool = True):
+                   true_len=None, kernel: str = "ref"):
     """Full-sequence SSD.  x: (B,S,D) -> (y, cache|None).
 
     Non-divisible S is front-padded with zeros to a chunk multiple: leading
@@ -144,6 +144,9 @@ def ssd_apply_full(p, x, cfg: ModelConfig, shd=_noop_shd, *, want_state: bool = 
     ``true_len`` (B,) int32 supports right-padded prompts: pad positions get
     dt=0 and x=0, making them exact no-ops for the state recurrence; the conv
     tail cache is gathered at per-row valid positions.
+
+    ``kernel`` (see :func:`repro.kernels.kernel_mode`): ``"ref"`` scans in
+    jnp, ``"pallas"``/``"interpret"`` run the SSD scan kernel.
     """
     B, S_in, D = x.shape
     Q = min(cfg.ssm_chunk, S_in)
@@ -166,12 +169,12 @@ def ssd_apply_full(p, x, cfg: ModelConfig, shd=_noop_shd, *, want_state: bool = 
     a = -jnp.exp(p["A_log"].astype(f32))     # (H,)
     da = dt * a                              # (B,S,H) <= 0
 
-    if use_pallas:
+    if kernel != "ref":
         from repro.kernels.ssd_scan.ops import ssd_chunked_scan
         Bh = _expand_heads(Bc, H)
         Ch = _expand_heads(Cc, H)
         y, h_last = ssd_chunked_scan(xc, Bh, Ch, dt, da, chunk=Q,
-                                     use_pallas=True, interpret=interpret)
+                                     interpret=kernel == "interpret")
     else:
         h0 = jnp.zeros((B, H, P, N), f32)
         h_last, y = _ssd_scan_chunks(xc, Bc, Cc, da, dt, h0, H, Q)

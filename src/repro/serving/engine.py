@@ -1,6 +1,7 @@
 """Continuous-batching inference engine (Orca-style iteration scheduling).
 
-One engine = one model replica on one device slice.  Static shapes
+One engine = one model replica on one device: its params, caches and every
+per-step input are committed to that device.  Static shapes
 throughout: a fixed decode batch of ``capacity`` rows over a ``RowPool``,
 prefill bucketed to a few lengths, per-row sampling parameter vectors — so
 the engine never recompiles as the request mix changes.
@@ -43,6 +44,13 @@ from repro.serving.prefix_cache import PrefixCache
 from repro.serving.request import Request, State
 from repro.serving.sampling import make_sampler
 from repro.serving.scheduler import Scheduler, SchedulerConfig, deadline_risk
+
+
+def _default_device() -> jax.Device:
+    """Where uncommitted arrays land here: the device of an enclosing
+    ``jax.default_device`` scope, else the first device."""
+    d = jax.config.jax_default_device
+    return d if isinstance(d, jax.Device) else jax.devices(d)[0]
 
 
 def _round_bucket(n: int, buckets: tuple[int, ...]) -> int:
@@ -94,6 +102,9 @@ class InferenceEngine:
                  enable_prefix_cache: bool = True,
                  seed: int = 0, tracer=None, metrics=None):
         assert kv_backend in ("dense", "paged")
+        # the replica's device: built under ``jax.default_device(d)`` (the
+        # orchestrator places each replica so), the engine lives on d
+        self.device = _default_device()
         self.cfg = cfg
         self.perf = perf
         self.model = make_model(cfg, perf)
@@ -108,12 +119,11 @@ class InferenceEngine:
         # with per-row state (SSM/conv, ring slots, enc-dec, vision prefix)
         # keep the dense RowPool backend — the engine chooses per config
         self.paged = kv_backend == "paged" and self.model.supports_paged()
-        if params is None:
-            params = P.init(jax.random.PRNGKey(seed), self.model.param_specs())
-        self.params = params
+        self.params = (self._init_on_device(self.model.param_specs(), seed)
+                       if params is None else self._move(params))
         self.scheduler = Scheduler(sched)
         self.pool = RowPool(capacity)
-        self.key = jax.random.PRNGKey(seed + 1)
+        self.key = self._move(jax.random.PRNGKey(seed + 1))
         # fixed batched-prefill group size (pow2) => one compile per bucket
         g = max(1, min(capacity, sched.max_prefill_per_step))
         self._group = 1 << (g - 1).bit_length()
@@ -147,15 +157,15 @@ class InferenceEngine:
                                                        block_size)
             pleaves = jax.tree.leaves(paged_specs, is_leaf=P.is_spec)
             self._pool_block_axes = [s.axes.index("kv_blocks") for s in pleaves]
-            self.caches = P.init(jax.random.PRNGKey(0), paged_specs)
+            self.caches = self._init_on_device(paged_specs)
             self.block_tables = np.full((capacity, self.max_blk), -1, np.int32)
             self._row_blocks: dict[int, list[int]] = {}
             self._row_reserved: dict[int, int] = {}
             self._reserved_total = 0
             self._hit_tokens_step = 0
         else:
-            self.caches = P.init(jax.random.PRNGKey(0), cache_specs)
-        self.tokens = jnp.zeros((capacity, 1), jnp.int32)
+            self.caches = self._init_on_device(cache_specs)
+        self.tokens = self._put(np.zeros((capacity, 1), np.int32))
         self.pos = np.zeros((capacity,), np.int32)
 
         # host-side per-row bookkeeping --------------------------------------
@@ -221,6 +231,23 @@ class InferenceEngine:
         self.scheduler.on_reject = self._trace_reject
 
     # ------------------------------------------------------------- internals
+    def _put(self, x):
+        """Commit host values (a pytree of numpy arrays) to this engine's
+        device as copies, so in-place updates of the host bookkeeping never
+        reach a step still in flight."""
+        return jax.device_put(x, self.device, may_alias=False)
+
+    def _move(self, tree):
+        """Commit device arrays (params, or a migration payload from a
+        replica on another device) to this engine's device; arrays already
+        there are not copied."""
+        return jax.device_put(tree, self.device)
+
+    def _init_on_device(self, specs, seed: int = 0):
+        """Initialise a spec tree directly on this engine's device."""
+        with jax.default_device(self.device):
+            return self._move(P.init(jax.random.PRNGKey(seed), specs))
+
     def _insert_rows_impl(self, pool_tree, new_tree, rows):
         """Scatter a batched prefill's rows into the pool along each leaf's
         batch axis.  Pad entries carry row == capacity and are dropped."""
@@ -334,8 +361,8 @@ class InferenceEngine:
         new = self.prefix.allocate(1)
         if new is None:
             raise RuntimeError("paged KV pool exhausted during copy-on-write")
-        self.caches = self._copy_block(self.caches, jnp.int32(old),
-                                       jnp.int32(new[0]))
+        self.caches = self._copy_block(self.caches, self._put(np.int32(old)),
+                                       self._put(np.int32(new[0])))
         blocks[block_idx] = new[0]
         self.block_tables[row, block_idx] = new[0]
         self.prefix.decref(old)
@@ -455,29 +482,28 @@ class InferenceEngine:
             temp[i] = req.sampling.temperature
             topk[i] = req.sampling.top_k
             topp[i] = req.sampling.top_p
-        batch: dict[str, Any] = {"tokens": jnp.asarray(toks)}
+        batch: dict[str, Any] = {"tokens": self._put(toks)}
         if self.cfg.num_vision_tokens:
             patches = np.zeros((G, self.cfg.num_vision_tokens, self.cfg.d_model),
                                np.float32)
             for i, req in enumerate(reqs):
                 if "patches" in req.extras:
                     patches[i] = np.asarray(req.extras["patches"])[0]
-            batch["patches"] = jnp.asarray(patches)
+            batch["patches"] = self._put(patches)
         if self.cfg.is_encoder_decoder:
             frames = np.zeros((G, self.cfg.encoder_seq, self.cfg.d_model),
                               np.float32)
             for i, req in enumerate(reqs):
                 if "frames" in req.extras:
                     frames[i] = np.asarray(req.extras["frames"])[0]
-            batch["frames"] = jnp.asarray(frames)
+            batch["frames"] = self._put(frames)
         logits, row_caches = self._prefill_fn(bucket, G)(
-            self.params, batch, jnp.asarray(true))
-        self.caches = self._insert(self.caches, row_caches, jnp.asarray(rows))
+            self.params, batch, self._put(true))
+        self.caches = self._insert(self.caches, row_caches, self._put(rows))
         # batched first tokens
         self.key, sk = jax.random.split(self.key)
         sampled = self._sampler(logits.astype(jnp.float32), sk,
-                                jnp.asarray(temp), jnp.asarray(topk),
-                                jnp.asarray(topp))
+                                *self._put((temp, topk, topp)))
         sampled = np.asarray(jax.device_get(sampled))
         prefix = self.cfg.num_vision_tokens or 0
         new_tokens = np.asarray(self.tokens).copy()
@@ -495,7 +521,7 @@ class InferenceEngine:
             self._trace_first_token(req, now)
             self._emit_first_token(req, t, now)
             self._maybe_finish_first(row, req, now)
-        self.tokens = jnp.asarray(new_tokens)
+        self.tokens = self._put(new_tokens)
         return sum(len(r.prompt) for r in reqs)
 
     def _admit_chunked(self, req: Request, now: float) -> int:
@@ -580,14 +606,13 @@ class InferenceEngine:
                 self._ensure_blocks(row, c0 + n)
                 self._ensure_writable(row, c0 // self.block_size)
         if self.paged:
+            toks, pos0, nval, bt = self._put((toks, pos0, nval,
+                                              self.block_tables))
             logits, self.caches = self._chunk_paged(
-                self.params, jnp.asarray(toks), jnp.asarray(pos0),
-                jnp.asarray(nval), self.caches,
-                jnp.asarray(self.block_tables))
+                self.params, toks, pos0, nval, self.caches, bt)
         else:
             logits, self.caches = self._chunk_program()(
-                self.params, self.caches, jnp.asarray(toks), jnp.asarray(pos0),
-                jnp.asarray(nval), jnp.asarray(fresh))
+                self.params, self.caches, *self._put((toks, pos0, nval, fresh)))
         self._fresh -= set(rows_n)
         done_rows = []
         for row, n in rows_n.items():
@@ -599,8 +624,7 @@ class InferenceEngine:
             return
         self.key, sk = jax.random.split(self.key)
         sampled = self._sampler(logits.astype(jnp.float32), sk,
-                                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                                jnp.asarray(self._topp))
+                                *self._put((self._temp, self._topk, self._topp)))
         sampled = np.asarray(jax.device_get(sampled))
         new_tokens = np.asarray(self.tokens).copy()
         for row in done_rows:
@@ -619,7 +643,7 @@ class InferenceEngine:
             self._trace_first_token(req, now)
             self._emit_first_token(req, t, now)
             self._maybe_finish_first(row, req, now)
-        self.tokens = jnp.asarray(new_tokens)
+        self.tokens = self._put(new_tokens)
 
     def _maybe_finish_first(self, row: int, req: Request, now: float) -> None:
         """A request can already be complete at its first (prefill) token —
@@ -924,25 +948,24 @@ class InferenceEngine:
                     self._ensure_blocks(row, int(self.pos[row]) + 1)
                     self._ensure_writable(
                         row, int(self.pos[row]) // self.block_size)
+                pos, bt, live = self._put((self.pos, self.block_tables, live))
                 logits, self.caches = self._decode_paged(
-                    self.params, self.tokens, jnp.asarray(self.pos),
-                    self.caches, jnp.asarray(self.block_tables),
-                    jnp.asarray(live))
+                    self.params, self.tokens, pos, self.caches, bt, live)
             elif self._prefilling:
                 live = np.ones((self.capacity,), bool)
                 for row in self._prefilling:
                     live[row] = False
                 logits, self.caches = self._decode_live(
-                    self.params, self.tokens, jnp.asarray(self.pos),
-                    self.caches, jnp.asarray(live))
+                    self.params, self.tokens, self._put(self.pos),
+                    self.caches, self._put(live))
             else:
                 logits, self.caches = self._decode(
-                    self.params, self.tokens, jnp.asarray(self.pos),
+                    self.params, self.tokens, self._put(self.pos),
                     self.caches)
             self.key, sk = jax.random.split(self.key)
-            sampled = self._sampler(logits.astype(jnp.float32), sk,
-                                    jnp.asarray(self._temp), jnp.asarray(self._topk),
-                                    jnp.asarray(self._topp))
+            sampled = self._sampler(
+                logits.astype(jnp.float32), sk,
+                *self._put((self._temp, self._topk, self._topp)))
             sampled = np.asarray(jax.device_get(sampled))
             t_dec = time.perf_counter() - t0
             new_tokens = np.asarray(self.tokens).copy()
@@ -960,7 +983,7 @@ class InferenceEngine:
                         or (stop is not None and t == stop)
                         or self.pos[row] >= self.max_len - 1):
                     self._retire(row, now)
-            self.tokens = jnp.asarray(new_tokens)
+            self.tokens = self._put(new_tokens)
 
         st = StepStats(t=now, decode_s=t_dec, prefill_s=t_pre,
                        n_prefill=admitted, occupancy=self.pool.used,
@@ -1047,7 +1070,7 @@ class InferenceEngine:
     def _gather_blocks(self, block_ids: list[int]):
         """Per-layer (n_blocks, block_size, ...) slabs for the given pool
         blocks — the data plane of a paged migration payload."""
-        ids = jnp.asarray(block_ids, jnp.int32)
+        ids = self._put(np.asarray(block_ids, np.int32))
         leaves = [jnp.take(pool, ids, axis=ax)
                   for pool, ax in zip(jax.tree.leaves(self.caches),
                                       self._pool_block_axes)]
@@ -1055,10 +1078,12 @@ class InferenceEngine:
 
     def _scatter_blocks(self, data, block_ids: list[int], lo: int) -> None:
         """Write payload slabs (skipping the first ``lo`` blocks — the
-        destination already holds them) into the given fresh pool blocks."""
+        destination already holds them) into the given fresh pool blocks.
+        Slabs from a replica on another device are moved here first."""
         if not block_ids:
             return
-        ids = jnp.asarray(block_ids, jnp.int32)
+        data = self._move(data)
+        ids = self._put(np.asarray(block_ids, np.int32))
         out = []
         for pool, d, ax in zip(jax.tree.leaves(self.caches),
                                jax.tree.leaves(data), self._pool_block_axes):
@@ -1218,8 +1243,9 @@ class InferenceEngine:
                                    * self.block_size)
             req.extras["adopt_hit_blocks"] = st["n_keep"]
         else:
-            self.caches = self._insert(self.caches, st["chunks"][0],
-                                       jnp.asarray([row], jnp.int32))
+            self.caches = self._insert(self.caches,
+                                       self._move(st["chunks"][0]),
+                                       self._put(np.asarray([row], np.int32)))
         self.pos[row] = payload["pos"]
         self._set_row_sampling(row, req)
         req.row = row

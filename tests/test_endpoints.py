@@ -346,3 +346,62 @@ def test_bare_orchestrator_stamps_default_tenant(cfg, rng):
     assert r.tenant is None
     orch.submit(r, now=0.0)
     assert r.tenant == "default"
+
+
+_PLACEMENT = """
+import jax
+from repro.configs import get_config
+from repro.core.endpoints import EndpointRegistry, ModelEndpoint
+from repro.serving import Request, SamplingParams, State
+
+cfg = get_config("qwen2-0.5b-smoke")
+reg = EndpointRegistry([
+    ModelEndpoint(name="a", model=cfg, kv_backend="paged", min_replicas=3,
+                  max_replicas=3, cold_start_steps=0),
+    ModelEndpoint(name="b", model=cfg, min_replicas=2, max_replicas=2,
+                  cold_start_steps=0)])
+a, b = reg.resolve("a"), reg.resolve("b")
+devs = jax.devices()
+# one replica per device across endpoints; shared only once all are taken
+assert [e.device for e in a.engines] == devs[:3]
+assert [e.device for e in b.engines] == [devs[3], devs[0]]
+for e in a.engines + b.engines:
+    leaves = jax.tree.leaves((e.params, e.caches, e.tokens))
+    assert all(x.devices() == {e.device} for x in leaves)
+# a live paged migration between replicas on different devices equals the
+# unmigrated run token for token
+prompt = list(range(3, 40))
+ref, mig = (Request(rid=i, model="a", prompt=prompt,
+                    sampling=SamplingParams(max_new_tokens=12))
+            for i in range(2))
+a.engines[0].submit(ref, 0.0)
+a.engines[1].submit(mig, 0.0)
+t = 0.0
+while not (mig.state is State.DECODE and len(mig.output) >= 4):
+    reg.step(t); t += 1
+ev = a.migrations.migrate(a.engines[1], a.engines[2], mig.rid, t, 1, 2)
+assert ev is not None
+assert any(q is mig for q in a.engines[2].row_req.values())
+while not (ref.done() and mig.done()):
+    reg.step(t); t += 1
+assert mig.output == ref.output and len(ref.output) == 12
+print("ok")
+"""
+
+
+def test_replicas_placed_one_per_device_and_migrate_across():
+    """Four virtual CPU devices in a child process (the device count is
+    fixed when JAX starts): replicas of every endpoint spread one per
+    device, each engine's arrays live on its device, and a paged migration
+    across devices is token-identical to the unmigrated run."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+               JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", _PLACEMENT], capture_output=True,
+                       text=True, env=env, cwd=repo, timeout=300)
+    assert r.returncode == 0 and r.stdout.strip() == "ok", \
+        r.stdout[-2000:] + r.stderr[-2000:]

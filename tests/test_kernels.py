@@ -30,8 +30,7 @@ def test_flash_attention(B, Sq, Skv, H, KV, d, window, dtype):
     q = jnp.asarray(RNG.normal(size=(B, Sq, H, d)), dtype)
     k = jnp.asarray(RNG.normal(size=(B, Skv, KV, d)), dtype)
     v = jnp.asarray(RNG.normal(size=(B, Skv, KV, d)), dtype)
-    out = attention(q, k, v, causal=True, window=window, use_pallas=True,
-                    interpret=True)
+    out = attention(q, k, v, causal=True, window=window, interpret=True)
     ref = attention_ref(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                         v.transpose(0, 2, 1, 3), causal=True,
                         window=window).transpose(0, 2, 1, 3)
@@ -60,8 +59,7 @@ def test_paged_attention(B, H, KV, d, nb, bs, maxb, dtype):
     kp = jnp.asarray(RNG.normal(size=(nb, bs, KV, d)), dtype)
     vp = jnp.asarray(RNG.normal(size=(nb, bs, KV, d)), dtype)
     tb, cl = jnp.asarray(table), jnp.asarray(ctx, jnp.int32)
-    out = paged_decode_attention(q, kp, vp, tb, cl, use_pallas=True,
-                                 interpret=True)
+    out = paged_decode_attention(q, kp, vp, tb, cl, interpret=True)
     ref = paged_attention_ref(q, kp, vp, tb, cl)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -81,8 +79,7 @@ def test_ssd_scan(b, S, H, P, N, Q):
     C = jnp.asarray(RNG.normal(size=(b, S, H, N)), jnp.float32) * 0.5
     dt = jnp.asarray(RNG.uniform(0.01, 0.2, size=(b, S, H)), jnp.float32)
     da = -dt * jnp.asarray(RNG.uniform(0.5, 2.0, size=(b, S, H)), jnp.float32)
-    y, h = ssd_chunked_scan(x, B, C, dt, da, chunk=Q, use_pallas=True,
-                            interpret=True)
+    y, h = ssd_chunked_scan(x, B, C, dt, da, chunk=Q, interpret=True)
     yr, hr = ssd_scan_ref(x, B, C, dt, da, chunk=Q)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=2e-4)
     np.testing.assert_allclose(np.asarray(h), np.asarray(hr), atol=2e-4)
@@ -96,8 +93,7 @@ def test_ssd_kernel_matches_sequential_recurrence():
     C = jnp.asarray(RNG.normal(size=(b, S, H, N)), jnp.float32)
     dt = jnp.asarray(RNG.uniform(0.05, 0.3, size=(b, S, H)), jnp.float32)
     da = -dt
-    y, h_last = ssd_chunked_scan(x, B, C, dt, da, chunk=Q, use_pallas=True,
-                                 interpret=True)
+    y, h_last = ssd_chunked_scan(x, B, C, dt, da, chunk=Q, interpret=True)
     hs = np.zeros((b, H, P, N), np.float32)
     ys = np.zeros((b, S, H, P), np.float32)
     for t in range(S):
@@ -112,19 +108,25 @@ def test_ssd_kernel_matches_sequential_recurrence():
 
 # --------------------------------------------------- model-level pallas
 @pytest.mark.parametrize("arch", ["qwen2-0.5b", "mamba2-780m", "mixtral-8x7b"])
-def test_model_with_pallas_matches_ref(arch):
+def test_model_with_pallas_matches_ref(arch, monkeypatch):
+    """The model's kernel path (forced to interpret mode through the one
+    platform choice, kernels.kernel_mode) against its jnp path."""
+    from repro import kernels
     from repro.configs import get_config
-    from repro.configs.perf import BASELINE, with_overrides
     from repro.models import params as P
     from repro.models.lm import make_model
     cfg = get_config(arch + "-smoke")
-    m_ref = make_model(cfg, BASELINE)
-    m_pal = make_model(cfg, with_overrides(BASELINE, use_pallas=True))
-    params = P.init(jax.random.PRNGKey(0), m_ref.param_specs())
+    model = make_model(cfg)
+    params = P.init(jax.random.PRNGKey(0), model.param_specs())
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
                               cfg.vocab_size).astype(jnp.int32)
-    lr, _ = jax.jit(lambda p, b: m_ref.prefill(p, b, 48))(params, {"tokens": toks})
-    lp, _ = jax.jit(lambda p, b: m_pal.prefill(p, b, 48))(params, {"tokens": toks})
+
+    def prefill(mode):
+        monkeypatch.setattr(kernels, "kernel_mode", lambda: mode)
+        return jax.jit(lambda p, b: model.prefill(p, b, 48))(
+            params, {"tokens": toks})[0]
+
+    lr, lp = prefill("ref"), prefill("interpret")
     rel = float(jnp.max(jnp.abs(lr - lp))) / (float(jnp.max(jnp.abs(lr))) + 1e-9)
     # MoE archs: bf16 noise can flip router top-k, so tolerance is looser
     assert rel < (6e-2 if cfg.num_experts else 2e-2), (arch, rel)

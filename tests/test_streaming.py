@@ -97,23 +97,29 @@ def test_streamed_tokens_match_output(backend, rng):
         assert r.t_first_token == e.t and e.index == 0
 
 
-def test_finish_reason_stop_token(rng):
+def test_finish_reason_stop_token():
+    import numpy as np
     cfg, eng = _mk()
+    # its own generator: the prompt must not depend on which tests ran
+    # earlier on this worker
+    rng = np.random.default_rng(11)
     prompt = [int(x) for x in rng.integers(0, cfg.vocab_size, 9)]
     # greedy-decode once to learn a token it will emit, then stop on it
     eng.submit(Request(rid=0, prompt=list(prompt),
                        sampling=SamplingParams(max_new_tokens=8)))
     ref = eng.run(max_steps=60)[0].output
     eng.finished.clear()
-    stop = ref[2]
+    # the engine stops at the first occurrence of the stop token, so stop
+    # on the first token not emitted earlier
+    cut = next(i for i in range(1, len(ref)) if ref[i] not in ref[:i])
     eng.submit(Request(rid=1, prompt=list(prompt),
                        sampling=SamplingParams(max_new_tokens=8,
-                                               stop_token=stop)))
+                                               stop_token=ref[cut])))
     demux, streamed, events = StreamDemux(), {}, []
     _collect(eng, demux, streamed, events)
     (req,) = eng.finished
     assert req.finish_reason == "stop"
-    assert streamed[1] == req.output == ref[:3]
+    assert streamed[1] == req.output == ref[:cut + 1]
     fin = [e for e in events if isinstance(e, FinishEvent)]
     assert fin[-1].reason == "stop"
 
