@@ -1,0 +1,69 @@
+"""Rate sweep of an open-loop cell (run by hand to find the knee; never by
+the benchmark's runs): one set-up, then a window at each rate.
+
+A rate's queue grows where, from the window's first half to its second,
+the median TTFT or the mean number of requests in the system (queued and
+served) rises by more than a quarter.  The knee is the highest rate swept
+below the first at which either does."""
+from __future__ import annotations
+
+import json
+import statistics
+import time
+
+import harness
+import stats
+
+
+def run(cell, seed: int, seconds: float, rates: list[float], devices, log) -> None:
+    conf, mix, c = cell.config, cell.mix, cell.cell
+    ref = harness.load_module(cell.conf_dir / conf["reference"])
+    cfg = harness.model_config(conf)
+    weights = harness.make_weights(conf, ref, seed, devices[0])
+    reg, orch = harness.build(conf, ref, weights, cfg)
+    harness.warm(reg, orch, conf, mix, conf["token_ids_below"])
+    rid0 = 0
+    for i, rate in enumerate(rates):
+        # a seed per rate, so no rate finds another's sessions in the cache
+        p = harness.plan(conf, mix, seed + i, seconds, c["ramp_s"], rate)
+        harness.prime(reg, conf, p.primes)
+        r = harness.drive(reg, conf, mix, p, drain_s=c["drain_s"], rid0=rid0)
+        rid0 += len(r.recs) + 1
+        att = harness.window_requests(r)
+        half = (r.w0 + r.w1) / 2
+        tt = stats.ttft(r.recs, r.w0, r.w1)
+        early = [v for x, v in zip(att, tt) if x.due < half]
+        late = [v for x, v in zip(att, tt) if x.due >= half]
+        pend = [p for t0, _, p in r.steps if r.w0 <= t0 < r.w1]
+        pend_early = statistics.fmean(pend[:len(pend) // 2] or [0])
+        pend_late = statistics.fmean(pend[len(pend) // 2:] or [0])
+        by = {(x.session, x.turn): x for x in r.recs}
+        spacing = [by[(x.session, x.turn + 1)].due - x.due for x in att
+                   if (x.session, x.turn + 1) in by]
+        tt_early = stats.percentile(early, 50) if early else None
+        tt_late = stats.percentile(late, 50) if late else None
+        line = {
+            "rate_per_s": rate, "attempted": len(att),
+            "failed": sum(1 for x in att if x.rejected or not x.times),
+            "ttft_p50_s": stats.percentile(tt, 50) if tt else None,
+            "ttft_p90_s": stats.percentile(tt, 90) if tt else None,
+            "ttft_p50_first_half_s": tt_early,
+            "ttft_p50_second_half_s": tt_late,
+            "itl_p95_s": stats.percentile(stats.gaps(r.recs, r.w0, r.w1), 95),
+            "output_tok_s": stats.tokens_in(r.recs, r.w0, r.w1) / seconds,
+            "prefix_hit_share": sum(x.req.prefix_hit_tokens for x in att)
+            / max(1, sum(len(x.prompt) for x in att)),
+            "steps": sum(1 for t0, _, _ in r.steps if r.w0 <= t0 < r.w1),
+            "pending_first_half": pend_early,
+            "pending_second_half": pend_late,
+            "turn_spacing_s": statistics.fmean(spacing) if spacing else None,
+            "grows": bool(tt_early is None or tt_late is None
+                          or tt_late > 1.25 * tt_early
+                          or pend_late > 1.25 * pend_early),
+        }
+        print(json.dumps(line), flush=True)
+        log(harness.lateness(r.recs))
+        t = time.perf_counter()
+        while reg.pending() and time.perf_counter() - t < 120:
+            reg.step()
+        reg.drain_events()
