@@ -75,9 +75,10 @@ class Metric:
         self.name = name
         self.help = help
         self.labelnames = tuple(labelnames)
+        self._labelset = frozenset(self.labelnames)
 
     def _key(self, labels: dict) -> tuple[str, ...]:
-        if set(labels) != set(self.labelnames):
+        if labels.keys() != self._labelset:
             raise ValueError(
                 f"{self.name}: got labels {sorted(labels)}, "
                 f"declared {sorted(self.labelnames)}")

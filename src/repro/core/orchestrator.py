@@ -33,7 +33,7 @@ from repro.core.profiler import Profiler
 from repro.core.scaling_policy import (ProactiveConfig,
                                        ProactiveScalingPolicy,
                                        ScalingSignals)
-from repro.core.tracing import Tracer, attribute_slo_misses
+from repro.core.tracing import HostSpans, Tracer, attribute_slo_misses
 from repro.core.transport import (DirectoryTransportClient,
                                   DirectoryTransportService, Transport)
 from repro.serving.engine import InferenceEngine
@@ -170,6 +170,9 @@ class Orchestrator:
         self.migrations.attach_metrics(self.metrics)
         self._steps = 0
         self._controls = 0
+        # host spans around the control tick and the transport pump
+        # (cluster.control / cluster.transport), seconds summed since start
+        self.spans = HostSpans()
         # goodput-loop accounting: tokens served since the last control
         # tick, the tick's step stamp, and the rids already scored against
         # their SLOs (each finished request is scored exactly once)
@@ -547,7 +550,8 @@ class Orchestrator:
                                              st.prefix_hit_tokens)
         self._steps += 1
         if self._steps % self.cfg.control_every_steps == 0:
-            self._control(now)
+            with self.spans("cluster.control"):
+                self._control(now)
             # migrations during the control tick emitted on their source
             # engines between steps; surface them in cluster step order
             for e in self.engines:
@@ -559,9 +563,10 @@ class Orchestrator:
             # On a shared fabric the EndpointRegistry passes
             # pump_transport=False and steps the Transport exactly once per
             # cluster step after every endpoint has pumped its migrations.
-            self.migrations.pump(now, self.transport)
-            if pump_transport:
-                self.transport.step()
+            with self.spans("cluster.transport"):
+                self.migrations.pump(now, self.transport)
+                if pump_transport:
+                    self.transport.step()
 
     def drain_events(self) -> list:
         """Return and clear the cluster event stream (cross-replica, in

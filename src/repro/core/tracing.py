@@ -31,13 +31,21 @@ tid = rid — load the file straight into https://ui.perfetto.dev), and
 :func:`attribute_slo_misses` decomposes each missed ``slo_ttft``/``slo_tpot``
 into queue-wait vs prefill vs decode-stall vs migration time.
 
-Host-side Python only (no jax, no serving imports): the serving layer
-imports this lazily, keeping the core<->serving import graph acyclic.
+Host spans inside a step (:class:`HostSpans`) are a second kind: named
+intervals of the engine's and the orchestrator's own host work
+(``engine.schedule``, ``engine.wait``, ``cluster.control``, ...), each
+both a ``jax.profiler.TraceAnnotation`` (a profile places it on the same
+clock as the device's operations) and a duration on ``time.perf_counter``.
+
+Host-side Python only (no serving imports, and ``jax.profiler`` only
+when a :class:`HostSpans` is made): the serving layer imports this lazily,
+keeping the core<->serving import graph acyclic.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
+import time
 from typing import Any, Iterable
 
 #: span names whose closed intervals must tile a request's lifetime
@@ -336,6 +344,52 @@ class Tracer:
     def write_chrome_trace(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.chrome_trace(), f)
+
+
+# ------------------------------------------------------------ host spans
+class HostSpans:
+    """Named host spans with their durations kept.
+
+    ``with spans("engine.decode"):`` opens a ``jax.profiler.TraceAnnotation``
+    of that name, so any profile of the process shows the span on the
+    device's clock, and adds its ``time.perf_counter`` duration to
+    ``seconds[name]`` (summed over the spans of that name since the last
+    :meth:`reset`).  The span object carries its own ``t0``/``t1``.  With
+    no profiler running a span costs one to two microseconds of host time.
+    """
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+        self.seconds: dict[str, float] = {}
+
+    def reset(self) -> None:
+        self.seconds = {}
+
+    def __call__(self, name: str) -> "HostSpan":
+        return HostSpan(self, name)
+
+
+class HostSpan:
+    """One span of a :class:`HostSpans`: its annotation and its ``t0``/``t1``
+    on ``time.perf_counter``."""
+
+    __slots__ = ("owner", "name", "ann", "t0", "t1")
+
+    def __init__(self, owner: HostSpans, name: str):
+        self.owner, self.name = owner, name
+        self.ann = owner._annotation(name)
+
+    def __enter__(self) -> "HostSpan":
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
+        s = self.owner.seconds
+        s[self.name] = s.get(self.name, 0.0) + self.t1 - self.t0
 
 
 # ------------------------------------------------------- SLO-miss attribution

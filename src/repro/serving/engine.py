@@ -53,6 +53,16 @@ def _default_device() -> jax.Device:
     return d if isinstance(d, jax.Device) else jax.devices(d)[0]
 
 
+def _jit(name: str, fn, **kw):
+    """``jax.jit`` of ``fn`` under a stable program name: the compiled
+    module is ``jit_<name>``, which is what a profile's ``XLA Modules`` line
+    shows for it."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **kw)
+
+
 def _round_bucket(n: int, buckets: tuple[int, ...]) -> int:
     for b in buckets:
         if n <= b:
@@ -89,6 +99,10 @@ class StepStats:
     # and the control plane consume these instead of per-step aggregates
     events: list[EngineEvent] = dataclasses.field(default_factory=list)
     preempted: int = 0              # rows displaced by the SLO guard this step
+    # host spans (core/tracing.HostSpans): the whole step, and the part of
+    # it the host spent blocked on the device (every ``engine.wait``)
+    step_s: float = 0.0
+    wait_s: float = 0.0
 
 
 class InferenceEngine:
@@ -185,26 +199,25 @@ class InferenceEngine:
         self._pending_adopt: dict[int, dict] = {}
         self._next_ticket = 0
 
-        # jitted programs -----------------------------------------------------
-        self._sampler = make_sampler()
-        self._decode = jax.jit(
-            lambda p, t, pos, c: self.model.decode_step(p, t, pos, c),
-            donate_argnums=(3,))
-        self._decode_live = jax.jit(self._decode_live_impl, donate_argnums=(3,))
-        self._prefill = {}  # (bucket, group) -> jit
-        self._insert = jax.jit(self._insert_rows_impl, donate_argnums=(0,))
+        # jitted programs, each under a stable name ---------------------------
+        self._sampler = make_sampler()                  # jit_sample
+        self._decode = _jit("decode", self.model.decode_step,
+                            donate_argnums=(3,))
+        self._decode_live = _jit("decode_live", self._decode_live_impl,
+                                 donate_argnums=(3,))
+        self._prefill = {}  # (bucket, group) -> jit_prefill_<bucket>x<group>
+        self._insert = _jit("insert_rows", self._insert_rows_impl,
+                            donate_argnums=(0,))
         self._chunk_fn = None  # lazily-built chunked-prefill program
         if self.paged:
-            self._decode_paged = jax.jit(
-                lambda p, t, pos, c, bt, live:
-                    self.model.decode_step_paged(p, t, pos, c, bt, live),
-                donate_argnums=(3,))
-            self._chunk_paged = jax.jit(
-                lambda p, t, pos0, nv, c, bt:
-                    self.model.prefill_chunk_paged(p, t, pos0, nv, c, bt),
-                donate_argnums=(4,))
-            self._copy_block = jax.jit(self._copy_block_impl,
-                                       donate_argnums=(0,))
+            self._decode_paged = _jit("decode_paged",
+                                      self.model.decode_step_paged,
+                                      donate_argnums=(3,))
+            self._chunk_paged = _jit("chunk_paged",
+                                     self.model.prefill_chunk_paged,
+                                     donate_argnums=(4,))
+            self._copy_block = _jit("copy_block", self._copy_block_impl,
+                                    donate_argnums=(0,))
         self.history: list[StepStats] = []
         self.finished: list[Request] = []
         # event stream (serving/events.py): appended by every request-visible
@@ -222,7 +235,8 @@ class InferenceEngine:
         # tracer/registry; the orchestrator and the disaggregated server
         # rebind every replica to shared ones via set_tracer/set_metrics.
         from repro.core.metrics import MetricsRegistry
-        from repro.core.tracing import Tracer
+        from repro.core.tracing import HostSpans, Tracer
+        self._spans = HostSpans()   # host spans of the current step
         self._rlabel = str(getattr(self, "replica_label", getattr(self, "lb_id", 0)))
         self.tracer = tracer if tracer is not None else Tracer()
         self.metrics: Any = None
@@ -292,12 +306,13 @@ class InferenceEngine:
                 logits, caches = self.model.prefill(p, batch, self.max_len,
                                                     true_len=true_len)
                 return logits, caches
-            self._prefill[key] = jax.jit(fn)
+            self._prefill[key] = _jit(f"prefill_{bucket}x{group}", fn)
         return self._prefill[key]
 
     def _chunk_program(self):
         if self._chunk_fn is None:
-            self._chunk_fn = jax.jit(self._chunk_impl, donate_argnums=(1,))
+            self._chunk_fn = _jit("chunk", self._chunk_impl,
+                                  donate_argnums=(1,))
         return self._chunk_fn
 
     # -------------------------------------------------- paged block plumbing
@@ -504,9 +519,11 @@ class InferenceEngine:
         self.key, sk = jax.random.split(self.key)
         sampled = self._sampler(logits.astype(jnp.float32), sk,
                                 *self._put((temp, topk, topp)))
-        sampled = np.asarray(jax.device_get(sampled))
+        with self._spans("engine.wait"):
+            sampled = np.asarray(jax.device_get(sampled))
         prefix = self.cfg.num_vision_tokens or 0
-        new_tokens = np.asarray(self.tokens).copy()
+        with self._spans("engine.wait"):
+            new_tokens = np.asarray(self.tokens).copy()
         for i, req in enumerate(reqs):
             t = int(sampled[i])
             row = req.row
@@ -625,8 +642,10 @@ class InferenceEngine:
         self.key, sk = jax.random.split(self.key)
         sampled = self._sampler(logits.astype(jnp.float32), sk,
                                 *self._put((self._temp, self._topk, self._topp)))
-        sampled = np.asarray(jax.device_get(sampled))
-        new_tokens = np.asarray(self.tokens).copy()
+        with self._spans("engine.wait"):
+            sampled = np.asarray(jax.device_get(sampled))
+        with self._spans("engine.wait"):
+            new_tokens = np.asarray(self.tokens).copy()
         for row in done_rows:
             req = self._prefilling.pop(row)
             del self._consumed[row]
@@ -741,7 +760,9 @@ class InferenceEngine:
             "engine_kv_frag", "Wasted tail-of-block KV slots fraction",
             ("replica",))
         self._h_step = registry.histogram(
-            "engine_step_seconds", "Wall seconds per step phase",
+            "engine_step_seconds",
+            "Host seconds per step phase (schedule / prefill / chunk / "
+            "decode / wait / observe), from the step's host spans",
             ("replica", "phase"))
         if self.paged:
             self._c_prefix = registry.counter(
@@ -764,10 +785,8 @@ class InferenceEngine:
                                     kind="true")
             self._c_prefill_tok.inc(st.prefill_tokens_padded, replica=rl,
                                     kind="padded")
-            self._h_step.observe(st.prefill_s, replica=rl, phase="prefill")
         if st.tokens_out:
             self._c_decode_tok.inc(st.tokens_out, replica=rl)
-            self._h_step.observe(st.decode_s, replica=rl, phase="decode")
         if st.n_prefill:
             self._c_admissions.inc(st.n_prefill, replica=rl)
         self._g_occupancy.set(st.occupancy / max(self.capacity, 1), replica=rl)
@@ -849,9 +868,77 @@ class InferenceEngine:
     # ------------------------------------------------------------------ step
     def step(self, now: float | None = None) -> StepStats:
         """One engine iteration: chunk continuations -> admit (batched
-        bucket prefills + new chunk starts) -> one decode step."""
+        bucket prefills + new chunk starts) -> one decode step.
+
+        Each phase runs under a host span (``self._spans``, see
+        core/tracing.HostSpans): ``engine.step`` around it all, then
+        ``engine.schedule``, ``engine.prefill`` (each bucket group),
+        ``engine.chunk``, ``engine.decode`` and ``engine.observe``, with an
+        ``engine.wait`` inside a phase wherever the host blocks on the
+        device.  Their durations feed ``StepStats`` and the
+        ``engine_step_seconds`` histogram."""
         now = time.perf_counter() if now is None else now
-        t0 = time.perf_counter()
+        sp = self._spans
+        sp.reset()
+        with sp("engine.step") as whole:
+            with sp("engine.schedule") as last:
+                (rows_n, groups, admitted, preempted, prefill_tokens,
+                 prefill_padded) = self._schedule(now)
+            for bucket in sorted(groups):
+                with sp("engine.prefill") as last:
+                    prefill_tokens += self._admit_batch(groups[bucket], bucket,
+                                                        now)
+                    prefill_padded += bucket * len(groups[bucket])
+            # one pool-wide chunk program for all advancing rows
+            if rows_n:
+                with sp("engine.chunk") as last:
+                    self._run_chunks(rows_n, now)
+            t_pre = last.t1 - whole.t0
+
+            tokens_out = 0
+            t_dec = 0.0
+            if self.row_req:
+                with sp("engine.decode") as dec:
+                    t_got, tokens_out = self._decode_rows(now)
+                t_dec = t_got - dec.t0
+
+            with sp("engine.observe"):
+                st = StepStats(t=now, decode_s=t_dec, prefill_s=t_pre,
+                               n_prefill=admitted, occupancy=self.pool.used,
+                               queue_depth=self.scheduler.depth(),
+                               tokens_out=tokens_out,
+                               prefill_tokens=prefill_tokens,
+                               chunk_rows=len(rows_n),
+                               prefill_tokens_padded=prefill_padded,
+                               prefill_tokens_true=prefill_tokens,
+                               events=self.drain_events(), preempted=preempted)
+                if self.paged:
+                    alloc = sum(len(b) for b in self._row_blocks.values()) \
+                        * self.block_size
+                    live_tok = int(sum(int(self.pos[r])
+                                       for r in self._row_blocks))
+                    st.prefix_hit_tokens = self._hit_tokens_step
+                    st.prefix_hit_rate = self.prefix.hit_rate()
+                    st.kv_blocks_used = self.prefix.used_blocks
+                    st.kv_blocks_cached = self.prefix.cached_blocks
+                    st.kv_util = self.prefix.utilization()
+                    st.kv_frag = 0.0 if alloc == 0 else 1.0 - live_tok / alloc
+                else:
+                    st.kv_util = self.pool.utilization()
+                self._observe_step(st)
+                self.history.append(st)
+        st.step_s = sp.seconds["engine.step"]
+        st.wait_s = sp.seconds.get("engine.wait", 0.0)
+        for name, sec in sp.seconds.items():
+            if name != "engine.step":
+                self._h_step.observe(sec, replica=self._rlabel,
+                                     phase=name.removeprefix("engine."))
+        return st
+
+    def _schedule(self, now: float):
+        """The SLO guard, the chunk continuations, ``scheduler.next_batch``
+        and admission.  Returns (rows_n, bucket groups, admitted, preempted,
+        prefill tokens, padded prefill tokens) so far."""
         budget = self.scheduler.cfg.prefill_token_budget
         # a non-positive budget would starve admission forever; clamp to the
         # minimum that still guarantees one (over-budget) pick per step
@@ -924,89 +1011,62 @@ class InferenceEngine:
             else:  # belt-and-braces: submit() already bounces these
                 req.state = State.REJECTED
                 self.rejected_long += 1
-        for bucket in sorted(groups):
-            prefill_tokens += self._admit_batch(groups[bucket], bucket, now)
-            prefill_padded += bucket * len(groups[bucket])
+        return (rows_n, groups, admitted, preempted, prefill_tokens,
+                prefill_padded)
 
-        # 3. one pool-wide chunk program for all advancing rows
-        if rows_n:
-            self._run_chunks(rows_n, now)
-        t_pre = time.perf_counter() - t0
-
-        # 4. decode
-        tokens_out = 0
-        t_dec = 0.0
-        if self.row_req:
-            t0 = time.perf_counter()
-            if self.paged:
-                # map the block each row's next token lands in (CoW'd if the
-                # prefix cache or another row still reads it); dead rows are
-                # masked so their writes drop instead of corrupting blocks
-                live = np.zeros((self.capacity,), bool)
-                for row in self.row_req:
-                    live[row] = True
-                    self._ensure_blocks(row, int(self.pos[row]) + 1)
-                    self._ensure_writable(
-                        row, int(self.pos[row]) // self.block_size)
-                pos, bt, live = self._put((self.pos, self.block_tables, live))
-                logits, self.caches = self._decode_paged(
-                    self.params, self.tokens, pos, self.caches, bt, live)
-            elif self._prefilling:
-                live = np.ones((self.capacity,), bool)
-                for row in self._prefilling:
-                    live[row] = False
-                logits, self.caches = self._decode_live(
-                    self.params, self.tokens, self._put(self.pos),
-                    self.caches, self._put(live))
-            else:
-                logits, self.caches = self._decode(
-                    self.params, self.tokens, self._put(self.pos),
-                    self.caches)
-            self.key, sk = jax.random.split(self.key)
-            sampled = self._sampler(
-                logits.astype(jnp.float32), sk,
-                *self._put((self._temp, self._topk, self._topp)))
-            sampled = np.asarray(jax.device_get(sampled))
-            t_dec = time.perf_counter() - t0
-            new_tokens = np.asarray(self.tokens).copy()
-            for row, req in list(self.row_req.items()):
-                t = int(sampled[row])
-                req.output.append(t)
-                req.token_times.append(now)
-                tokens_out += 1
-                self.pos[row] += 1
-                new_tokens[row, 0] = t
-                self.emit_event(TokenEvent(t=now, rid=req.rid, token=t,
-                                           index=len(req.output) - 1))
-                stop = req.sampling.stop_token
-                if (len(req.output) >= req.sampling.max_new_tokens
-                        or (stop is not None and t == stop)
-                        or self.pos[row] >= self.max_len - 1):
-                    self._retire(row, now)
-            self.tokens = self._put(new_tokens)
-
-        st = StepStats(t=now, decode_s=t_dec, prefill_s=t_pre,
-                       n_prefill=admitted, occupancy=self.pool.used,
-                       queue_depth=self.scheduler.depth(), tokens_out=tokens_out,
-                       prefill_tokens=prefill_tokens, chunk_rows=len(rows_n),
-                       prefill_tokens_padded=prefill_padded,
-                       prefill_tokens_true=prefill_tokens,
-                       events=self.drain_events(), preempted=preempted)
+    def _decode_rows(self, now: float) -> tuple[float, int]:
+        """One decode step over the live rows, their sampled tokens, and
+        retirement.  Returns (when the sampled tokens reached the host,
+        tokens emitted)."""
         if self.paged:
-            alloc = sum(len(b) for b in self._row_blocks.values()) \
-                * self.block_size
-            live_tok = int(sum(int(self.pos[r]) for r in self._row_blocks))
-            st.prefix_hit_tokens = self._hit_tokens_step
-            st.prefix_hit_rate = self.prefix.hit_rate()
-            st.kv_blocks_used = self.prefix.used_blocks
-            st.kv_blocks_cached = self.prefix.cached_blocks
-            st.kv_util = self.prefix.utilization()
-            st.kv_frag = 0.0 if alloc == 0 else 1.0 - live_tok / alloc
+            # map the block each row's next token lands in (CoW'd if the
+            # prefix cache or another row still reads it); dead rows are
+            # masked so their writes drop instead of corrupting blocks
+            live = np.zeros((self.capacity,), bool)
+            for row in self.row_req:
+                live[row] = True
+                self._ensure_blocks(row, int(self.pos[row]) + 1)
+                self._ensure_writable(
+                    row, int(self.pos[row]) // self.block_size)
+            pos, bt, live = self._put((self.pos, self.block_tables, live))
+            logits, self.caches = self._decode_paged(
+                self.params, self.tokens, pos, self.caches, bt, live)
+        elif self._prefilling:
+            live = np.ones((self.capacity,), bool)
+            for row in self._prefilling:
+                live[row] = False
+            logits, self.caches = self._decode_live(
+                self.params, self.tokens, self._put(self.pos),
+                self.caches, self._put(live))
         else:
-            st.kv_util = self.pool.utilization()
-        self._observe_step(st)
-        self.history.append(st)
-        return st
+            logits, self.caches = self._decode(
+                self.params, self.tokens, self._put(self.pos),
+                self.caches)
+        self.key, sk = jax.random.split(self.key)
+        sampled = self._sampler(
+            logits.astype(jnp.float32), sk,
+            *self._put((self._temp, self._topk, self._topp)))
+        with self._spans("engine.wait") as got:
+            sampled = np.asarray(jax.device_get(sampled))
+        with self._spans("engine.wait"):
+            new_tokens = np.asarray(self.tokens).copy()
+        tokens_out = 0
+        for row, req in list(self.row_req.items()):
+            t = int(sampled[row])
+            req.output.append(t)
+            req.token_times.append(now)
+            tokens_out += 1
+            self.pos[row] += 1
+            new_tokens[row, 0] = t
+            self.emit_event(TokenEvent(t=now, rid=req.rid, token=t,
+                                       index=len(req.output) - 1))
+            stop = req.sampling.stop_token
+            if (len(req.output) >= req.sampling.max_new_tokens
+                    or (stop is not None and t == stop)
+                    or self.pos[row] >= self.max_len - 1):
+                self._retire(row, now)
+        self.tokens = self._put(new_tokens)
+        return got.t1, tokens_out
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
         steps = 0
