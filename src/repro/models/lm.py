@@ -443,7 +443,10 @@ class LM:
         of :meth:`prefill_chunk`).  A prefix-cache hit simply starts the
         first chunk at pos0 = n_cached: the shared blocks already hold those
         positions' KV, so the skipped tokens are never embedded or attended.
-        Rows with n_valid == 0 are exact no-ops."""
+        The batch is any set of rows (the engine passes the rows that
+        advance, a fixed group at a time, with their block-table rows): the
+        pools are reached only through ``block_table``.  Rows with
+        n_valid == 0 and an unmapped table are exact no-ops."""
         cfg = self.cfg
         x = L.embed_apply(params["embed"], tokens, cfg)
         x = shd(x, ("batch", "act_seq", "embed"))

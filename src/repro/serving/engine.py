@@ -15,8 +15,10 @@ Prefill is a pipeline, not a one-request-at-a-time call:
   slices append into the row's KV/SSM cache across steps instead of raising,
   so a long prompt is a supported scenario and per-step prefill work stays
   bounded (``SchedulerConfig.prefill_token_budget``) to limit head-of-line
-  blocking of running decodes.  One chunk program covers the whole pool —
-  idle rows ride along as exact no-ops.
+  blocking of running decodes.  On the dense backend one chunk program
+  covers the whole pool — idle rows ride along as exact no-ops; the paged
+  backend, whose pools are indexed only through the block table, runs its
+  chunk program over the advancing rows alone, a fixed group at a time.
 
 The control plane (core/) consumes the per-step telemetry this engine
 emits; the same engine class serves as the *real* backend behind the
@@ -81,10 +83,12 @@ class StepStats:
     tokens_out: int
     prefill_tokens: int = 0     # prompt tokens prefilled this step (all paths)
     chunk_rows: int = 0         # rows advanced by the chunked-prefill program
+    chunk_calls: int = 0        # launches of the chunk program (paged: one
+                                # per group of ``_group`` rows)
     # cost-model split: what the step computed vs. what requests needed.
     # Dense charges bucket round-up / chunk slice width; the paged backend's
-    # pool-wide chunk program masks rather than pads per row, so there
-    # padded == true (both cache-aware: prefix hits are never charged).
+    # chunk program masks rather than pads per row, so there padded == true
+    # (both cache-aware: prefix hits are never charged).
     prefill_tokens_padded: int = 0  # incl. bucket round-up / chunk slice width
     prefill_tokens_true: int = 0    # actual prompt tokens advanced
     # paged-KV / prefix-cache telemetry (zero on the dense backend)
@@ -213,9 +217,12 @@ class InferenceEngine:
             self._decode_paged = _jit("decode_paged",
                                       self.model.decode_step_paged,
                                       donate_argnums=(3,))
-            self._chunk_paged = _jit("chunk_paged",
-                                     self.model.prefill_chunk_paged,
-                                     donate_argnums=(4,))
+            self._chunk_paged = _jit("chunk_paged", self._chunk_paged_impl,
+                                     donate_argnums=(1, 2))
+            # last-position logits of the rows that chunked, at their pool
+            # rows: the sampler keeps its one (capacity, V) shape
+            self._chunk_logits = self._put(
+                np.zeros((capacity, cfg.vocab_size), np.float32))
             self._copy_block = _jit("copy_block", self._copy_block_impl,
                                     donate_argnums=(0,))
         self.history: list[StepStats] = []
@@ -298,6 +305,16 @@ class InferenceEngine:
         occupant's ring positions or SSM state into a new prompt."""
         caches = self._select_rows(fresh, self._reset_vals, caches)
         return self.model.prefill_chunk(params, tokens, pos0, n_valid, caches)
+
+    def _chunk_paged_impl(self, params, caches, logits, tokens, pos0, n_valid,
+                          block_table, rows):
+        """One chunk for a group of rows (tokens (G, C); pos0, n_valid,
+        rows (G,); their block-table rows (G, max_blk)); the group's logits
+        land in the (capacity, V) buffer at ``rows``, where pad entries'
+        row == capacity drops them."""
+        new, caches = self.model.prefill_chunk_paged(
+            params, tokens, pos0, n_valid, caches, block_table)
+        return logits.at[rows].set(new, mode="drop"), caches
 
     def _prefill_fn(self, bucket: int, group: int):
         key = (bucket, group)
@@ -600,9 +617,11 @@ class InferenceEngine:
         self._hit_tokens_step += n_hit
         return row
 
-    def _run_chunks(self, rows_n: dict[int, int], now: float) -> None:
-        """Advance the selected mid-prefill rows by one chunk each (single
-        pool-wide program call); promote rows that consumed their prompt."""
+    def _run_chunks(self, rows_n: dict[int, int], now: float) -> int:
+        """Advance the selected mid-prefill rows by one chunk each; promote
+        rows that consumed their prompt.  The dense backend makes one
+        pool-wide program call, the paged backend one call per group of
+        advancing rows (:meth:`_chunk_groups`).  Returns the calls."""
         B, C = self.capacity, self.chunk
         toks = np.zeros((B, C), np.int32)
         pos0 = np.zeros((B,), np.int32)
@@ -623,13 +642,12 @@ class InferenceEngine:
                 self._ensure_blocks(row, c0 + n)
                 self._ensure_writable(row, c0 // self.block_size)
         if self.paged:
-            toks, pos0, nval, bt = self._put((toks, pos0, nval,
-                                              self.block_tables))
-            logits, self.caches = self._chunk_paged(
-                self.params, toks, pos0, nval, self.caches, bt)
+            calls = self._chunk_groups(list(rows_n), toks, pos0, nval)
+            logits = self._chunk_logits
         else:
             logits, self.caches = self._chunk_program()(
                 self.params, self.caches, *self._put((toks, pos0, nval, fresh)))
+            calls = 1
         self._fresh -= set(rows_n)
         done_rows = []
         for row, n in rows_n.items():
@@ -638,7 +656,7 @@ class InferenceEngine:
             if self._consumed[row] >= len(self._prefilling[row].prompt):
                 done_rows.append(row)
         if not done_rows:
-            return
+            return calls
         self.key, sk = jax.random.split(self.key)
         sampled = self._sampler(logits.astype(jnp.float32), sk,
                                 *self._put((self._temp, self._topk, self._topp)))
@@ -663,6 +681,27 @@ class InferenceEngine:
             self._emit_first_token(req, t, now)
             self._maybe_finish_first(row, req, now)
         self.tokens = self._put(new_tokens)
+        return calls
+
+    def _chunk_groups(self, rows: list[int], toks, pos0, nval) -> int:
+        """Paged chunk calls over ``rows`` (admission order), ``self._group``
+        at a time, so the program has one shape whatever the capacity; the
+        inputs are indexed by pool row.  The pools and the logits buffer are
+        donated from call to call.  A short group is padded with pool row
+        ``capacity``: an idle entry (n_valid 0, unmapped block-table row)
+        that writes nothing and whose logits drop.  Returns the calls."""
+        G = self._group
+        idx = np.asarray(rows, np.int32)
+        idx = np.pad(idx, (0, -len(idx) % G), constant_values=self.capacity)
+        toks = np.pad(toks, ((0, 1), (0, 0)))
+        pos0, nval = np.pad(pos0, (0, 1)), np.pad(nval, (0, 1))
+        bt = np.pad(self.block_tables, ((0, 1), (0, 0)), constant_values=-1)
+        for i in range(0, len(idx), G):
+            g = idx[i:i + G]
+            self._chunk_logits, self.caches = self._chunk_paged(
+                self.params, self.caches, self._chunk_logits,
+                *self._put((toks[g], pos0[g], nval[g], bt[g], g)))
+        return len(idx) // G
 
     def _maybe_finish_first(self, row: int, req: Request, now: float) -> None:
         """A request can already be complete at its first (prefill) token —
@@ -736,6 +775,9 @@ class InferenceEngine:
             ("replica", "kind"))
         self._c_decode_tok = registry.counter(
             "engine_decode_tokens_total", "Decode tokens emitted", ("replica",))
+        self._c_chunk_calls = registry.counter(
+            "engine_chunk_calls_total",
+            "Launches of the chunked-prefill program", ("replica",))
         self._c_admissions = registry.counter(
             "engine_admissions_total", "Requests admitted onto a row",
             ("replica",))
@@ -787,6 +829,8 @@ class InferenceEngine:
                                     kind="padded")
         if st.tokens_out:
             self._c_decode_tok.inc(st.tokens_out, replica=rl)
+        if st.chunk_calls:
+            self._c_chunk_calls.inc(st.chunk_calls, replica=rl)
         if st.n_prefill:
             self._c_admissions.inc(st.n_prefill, replica=rl)
         self._g_occupancy.set(st.occupancy / max(self.capacity, 1), replica=rl)
@@ -889,10 +933,10 @@ class InferenceEngine:
                     prefill_tokens += self._admit_batch(groups[bucket], bucket,
                                                         now)
                     prefill_padded += bucket * len(groups[bucket])
-            # one pool-wide chunk program for all advancing rows
+            chunk_calls = 0
             if rows_n:
                 with sp("engine.chunk") as last:
-                    self._run_chunks(rows_n, now)
+                    chunk_calls = self._run_chunks(rows_n, now)
             t_pre = last.t1 - whole.t0
 
             tokens_out = 0
@@ -909,6 +953,7 @@ class InferenceEngine:
                                tokens_out=tokens_out,
                                prefill_tokens=prefill_tokens,
                                chunk_rows=len(rows_n),
+                               chunk_calls=chunk_calls,
                                prefill_tokens_padded=prefill_padded,
                                prefill_tokens_true=prefill_tokens,
                                events=self.drain_events(), preempted=preempted)

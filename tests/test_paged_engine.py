@@ -2,6 +2,7 @@
 caching (hits skip prefill, CoW on shared tails), per-block telemetry, and
 the padded/true cost-model split."""
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.serving import InferenceEngine, Request, SamplingParams
@@ -345,3 +346,78 @@ def test_orchestrator_paged_prefix_affinity(rng):
     assert hits > 0
     assert any(orch.profiler.util[t].count() for t in orch.profiler.util
                if t.endswith("/kv"))
+
+
+def _long_prompts(cfg, n_rows, seed=5):
+    """Prompts of three or four 16-token chunks, one per row."""
+    r = np.random.default_rng(seed)
+    return [[int(x) for x in r.integers(0, cfg.vocab_size, n)]
+            for n in r.integers(40, 62, n_rows)]
+
+
+def test_chunk_groups_match_dense_and_alone():
+    """Eight rows chunk at once on a capacity-8 pool, so a step makes two
+    group calls of four: the tokens equal the dense backend's pool-wide
+    chunk program and each request served alone; the chunk program
+    compiled one shape and the sampler saw one."""
+    cfg = get_config(ARCH)
+    prompts = _long_prompts(cfg, 8)
+    outs = {}
+    for backend in ("dense", "paged"):
+        _, eng = _mk(backend, capacity=8, max_len=96)
+        sampler, shapes = eng._sampler, set()
+
+        def spy(logits, *args):
+            shapes.add(logits.shape)
+            return sampler(logits, *args)
+
+        eng._sampler = spy
+        _submit_all(eng, cfg, prompts)
+        outs[backend] = {r.rid: r.output for r in eng.run(max_steps=300)}
+        if backend == "paged":
+            assert max(s.chunk_calls for s in eng.history) >= 2
+            assert eng._chunk_paged._cache_size() == 1
+            assert shapes == {(8, cfg.vocab_size)}, shapes
+    assert len(outs["paged"]) == len(prompts)
+    assert outs["paged"] == outs["dense"]
+    _, alone = _mk("paged", capacity=8, max_len=96)
+    for i, p in enumerate(prompts):
+        _submit_all(alone, cfg, [p], rid0=i)
+        alone.run(max_steps=100)
+        assert alone.finished[-1].output == outs["paged"][i]
+        assert max(s.chunk_calls for s in alone.history) == 1
+
+
+def test_chunk_tokens_do_not_depend_on_capacity():
+    """A row's tokens do not depend on the pool's capacity or on which
+    rows share its group: the same requests on a capacity-4 and a
+    capacity-16 paged engine give identical tokens."""
+    cfg = get_config(ARCH)
+    prompts = _long_prompts(cfg, 10, seed=6)
+    outs = []
+    for capacity in (4, 16):
+        _, eng = _mk("paged", capacity=capacity, max_len=96)
+        assert eng._group == 4
+        _submit_all(eng, cfg, prompts)
+        outs.append({r.rid: r.output for r in eng.run(max_steps=400)})
+    assert len(outs[0]) == len(prompts)
+    assert outs[0] == outs[1]
+
+
+@pytest.mark.parametrize("backend", ["dense", "paged"])
+def test_chunk_calls_counted_per_step(backend):
+    """``StepStats.chunk_calls`` is ceil(chunk_rows / group) on the paged
+    backend and one pool-wide call on the dense one; the registry's
+    ``engine_chunk_calls_total`` is their sum."""
+    from repro.core.metrics import parse_exposition
+    cfg, eng = _mk(backend, capacity=8, max_len=96)
+    _submit_all(eng, cfg, _long_prompts(cfg, 7, seed=7) + [[1, 2, 3]])
+    eng.run(max_steps=300)
+    G = eng._group
+    for st in eng.history:
+        per_call = G if backend == "paged" else eng.capacity
+        assert st.chunk_calls == -(-st.chunk_rows // per_call)
+    calls = sum(st.chunk_calls for st in eng.history)
+    assert calls > 0
+    exp = parse_exposition(eng.metrics.render())
+    assert exp.value("engine_chunk_calls_total", replica="0") == calls

@@ -137,6 +137,16 @@ def test_profile_spans_nest_and_programs_are_named(backend, tmp_path):
             else {"decode", "decode_live", "chunk", "prefill_8x4",
                   "prefill_16x4", "insert_rows", "sample"})
     assert want <= top, top
+    if backend == "paged":
+        # a step whose rows chunk launches the group calls, the decode and
+        # the sampler, and no eager scatter or gather of their inputs
+        chunk_steps = [s for s in steps
+                       if any(_inside(c, [s]) for c in named("engine.chunk"))]
+        launched = {c[0] for c in calls
+                    if c[0] in top and _inside(c, chunk_steps)}
+        assert "chunk_paged" in launched, launched
+        assert launched - EAGER <= {"chunk_paged", "decode_paged", "sample"}, \
+            launched
 
 
 def test_every_engine_program_has_a_stable_name():
