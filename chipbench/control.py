@@ -62,10 +62,12 @@ def reading(cell, seed: int, seconds: float, control: bool, devices) -> dict:
     p = harness.plan(conf, mix, seed, seconds, c["ramp_s"])
     harness.prime(reg, conf, p.primes)
     r = harness.drive(reg, conf, mix, p, drain_s=c["drain_s"])
+    moved = {e.rid for e in orch.migrations.events}
     del reg, orch
     gc.collect()
     jax.clear_caches()
-    chosen = harness.sample(r.recs, seed, c["check_tokens"], c["check_requests"])
+    chosen = harness.sample(r.recs, seed, c["check_tokens"], c["check_requests"],
+                            moved)
     t = time.perf_counter()
     prog, ctrl = harness.reference_gaps(ref, conf["model"], weights, chosen,
                                         control=control)

@@ -52,9 +52,10 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> SimpleNamespace:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     conf_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
-    conf = json.loads((root / conf_entry["file"]).read_text())
     mix = json.loads((HERE / "traffic" / f"{w['traffic']}.json").read_text())
     cell = json.loads((HERE / "cells" / f"{name}.json").read_text())
+    conf = deployment(json.loads((root / conf_entry["file"]).read_text()),
+                      cell, w["chips"])
 
     def mine(metric):
         return name in metric.get("workloads", [name])
@@ -63,6 +64,21 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> SimpleNamespace:
         end_to_end=[m for m in bench["end_to_end"] if mine(m)],
         per_layer=[m for m in bench["per_layer"] if mine(m)],
         conf_dir=(root / conf_entry["file"]).parent)
+
+
+DEPLOYMENT = ("replicas", "lb_policy")
+
+
+def deployment(conf: dict, cell: dict, chips: int) -> dict:
+    """The configuration as the cell deploys it: the cell file's
+    ``replicas`` and ``lb_policy``, where it names them, in place of the
+    configuration's; every other key from the configuration.  A cell may
+    not ask for more replicas than chips."""
+    out = dict(conf, **{k: cell[k] for k in DEPLOYMENT if k in cell})
+    if out["replicas"] > chips:
+        raise SystemExit(f"chipbench: {out['replicas']} replicas on {chips} "
+                         f"chip(s): a cell runs one replica a chip at most")
+    return out
 
 
 def load_module(path: pathlib.Path):
@@ -226,15 +242,17 @@ def warm(reg, orch, conf: dict, mix: dict, vocab: int) -> None:
                         rng.integers(0, vocab, 40).tolist()], 2)
 
 
-def prime(reg, conf: dict, rounds: list[list[list[int]]]) -> None:
+def prime(reg, conf: dict, rounds: list[list[list[int]]]) -> list[list]:
     """Leave each round's prompts in the prefix cache, as a deployment
     that served them before holds them: each is served once with a
     one-token answer (the paged backend indexes a finished request's
-    blocks), a round after the one before it has finished."""
+    blocks), a round after the one before it has finished.  Returns each
+    round's served requests (``Request.replica`` says where each went)."""
     from repro.serving import Request, SamplingParams
     if conf["engine"]["kv_backend"] != "paged":
-        return
+        return [[] for _ in rounds]
     rid = WARM_RID
+    served = []
     for prompts in rounds:
         reqs = []
         for p in prompts:
@@ -249,6 +267,8 @@ def prime(reg, conf: dict, rounds: list[list[list[int]]]) -> None:
         else:
             raise RuntimeError("priming requests unfinished")
         reg.drain_events()
+        served.append(reqs)
+    return served
 
 
 def plan(conf: dict, mix: dict, seed: int, seconds: float, ramp_s: float,
@@ -390,21 +410,37 @@ def drive(reg, conf: dict, mix: dict, p: SimpleNamespace, *, drain_s: float,
 
 
 # ---------------------------------------------------------- correctness
-def sample(recs, seed: int, want_tokens: int, max_reqs: int) -> list[Rec]:
-    """Finished requests drawn from the seed, the longest first, until
+def sample(recs, seed: int, want_tokens: int, max_reqs: int,
+           moved=frozenset()) -> list[Rec]:
+    """Finished requests drawn from the seed: the longest first, then the
+    first drawn of each replica that has none in the sample yet (so every
+    replica's copy of the weights is checked), then those whose rid is in
+    ``moved`` (requests that migrated between replicas, so the exchange of
+    their cache is checked) while fewer than ``max_reqs``, then more until
     ``want_tokens`` served tokens or ``max_reqs`` requests."""
     done = [r for r in recs if r.finish is not None and r.tokens]
     if not done:
         return []
     longest = max(done, key=lambda r: (len(r.prompt) + len(r.tokens), r.rid))
     rest = [r for r in done if r is not longest]
-    rng = np.random.default_rng([seed, 5])
-    out, n = [longest], len(longest.tokens)
-    for i in rng.permutation(len(rest)):
+    order = [rest[i] for i in np.random.default_rng([seed, 5]).permutation(len(rest))]
+    out, seen = [longest], {longest.req.replica}
+    for r in order:
+        if r.req.replica not in seen:
+            out.append(r)
+            seen.add(r.req.replica)
+    picked = {r.rid for r in out}
+    for r in order:
+        if r.rid in moved and r.rid not in picked and len(out) < max_reqs:
+            out.append(r)
+            picked.add(r.rid)
+    n = sum(len(r.tokens) for r in out)
+    for r in order:
         if n >= want_tokens or len(out) >= max_reqs:
             break
-        out.append(rest[i])
-        n += len(rest[i].tokens)
+        if r.rid not in picked:
+            out.append(r)
+            n += len(r.tokens)
     return out
 
 
@@ -548,13 +584,25 @@ class Watch:
         c = [x for x in self.compiles if w0 <= x[0] < w1]
         g = [d for t, d in self.gc if w0 <= t < w1]
         return (f"in the window: {len(c)} trace/compile/cache events "
-                f"({sum(x[2] for x in c):.3f} s), {len(g)} garbage "
+                f"({sum(x[2] for x in c):.3f} s, at "
+                f"{[round(x[0] - w0, 3) for x in c[:10]]} s), {len(g)} garbage "
                 f"collections (longest {max(g, default=0.0):.4f} s)")
 
 
-def memory_peak(devices) -> int:
-    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-               for d in devices)
+def migrations(mgr, w0: float, w1: float) -> str:
+    """The router's moves of live requests between replicas in [w0, w1)."""
+    moved = [e for e in mgr.events if w0 <= e.t < w1]
+    failed = [f for f in mgr.failures if w0 <= f.t < w1]
+    return (f"migrations in the window: {len(moved)} moved "
+            f"({sum(e.bytes for e in moved)} bytes, at "
+            f"{[round(e.t - w0, 3) for e in moved]} s), {len(failed)} failed "
+            f"({sorted({f.reason for f in failed})})")
+
+
+def memory_peaks(devices) -> list[int]:
+    """Each device's peak bytes in use so far."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
 
 
 # ------------------------------------------------------------------ run
@@ -572,7 +620,7 @@ def run(cell: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
         f"{[str(e.device) for e in orch.engines]}")
     warm(reg, orch, conf, mix, conf["token_ids_below"])
     p = plan(conf, mix, seed, seconds, c["ramp_s"])
-    prime(reg, conf, p.primes)
+    primed = prime(reg, conf, p.primes)[-1]
     setup_s = time.perf_counter() - t_start
     log(f"set-up {setup_s:.3f} s")
 
@@ -591,11 +639,19 @@ def run(cell: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
         f"({stats.tokens_in(r.recs, r.w0, half)} in the first half, "
         f"{stats.tokens_in(r.recs, half, r.w1)} in the second), "
         f"{len(r.steps)} steps")
-    peak = memory_peak(devices)
+    in_use = memory_peaks(devices)
+    peak = max(in_use)
+    if len(devices) > 1:
+        log(f"peak bytes in use per chip: {in_use}")
     histories = [list(e.history) for e in orch.engines]
+    if len(orch.engines) > 1:
+        log(migrations(orch.migrations, r.w0, r.w1))
+    primed = [(q.prompt, q.replica) for q in primed]
+    moved = {e.rid for e in orch.migrations.events}
     for x in r.recs:          # keep what the readers need, drop the program
         x.req = SimpleNamespace(output=list(x.req.output), t_admit=x.req.t_admit,
-                                prefix_hit_tokens=x.req.prefix_hit_tokens)
+                                prefix_hit_tokens=x.req.prefix_hit_tokens,
+                                replica=x.req.replica)
     del reg, orch
     gc.collect()
     jax.clear_caches()
@@ -618,7 +674,8 @@ def run(cell: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
         # the traced seconds, which the profiler slows
         info = SimpleNamespace(m=conf["model"], conf=conf, recs=r.recs,
                                w0=r.w0, w1=r.t_trace, traced=r.traced,
-                               trace=summary, peaks=peaks, histories=histories)
+                               trace=summary, peaks=peaks, histories=histories,
+                               primed=primed)
         for spec in cell.per_layer:
             reader = load_module(HERE / "layer_metrics" / f"{spec['name']}.py")
             v = reader.read(info)
@@ -630,14 +687,16 @@ def run(cell: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
             metrics[spec["name"]] = {"value": end_to_end(spec["name"], r, setup_s),
                                      "unit": spec["unit"]}
 
-    chosen = sample(r.recs, seed, c["check_tokens"], c["check_requests"])
+    chosen = sample(r.recs, seed, c["check_tokens"], c["check_requests"], moved)
     t = time.perf_counter()
     gaps, _ = (reference_gaps(ref, conf["model"], weights, chosen)
                if chosen else (np.zeros(0), None))
     worst = float(gaps.max(initial=0.0))
     faults = stream_faults(r.recs)
-    log(f"reference: {len(chosen)} requests, {len(gaps)} served tokens, "
-        f"{time.perf_counter() - t:.3f} s")
+    log(f"reference: {len(chosen)} requests from replicas "
+        f"{sorted({x.req.replica for x in chosen})} ({len(moved)} migrated, "
+        f"{sum(x.rid in moved for x in chosen)} of them checked), {len(gaps)} "
+        f"served tokens, {time.perf_counter() - t:.3f} s")
     correct, checks = judge(c, worst, faults, len(gaps), bool(attempted))
     out = {"correct": correct, "attempted": len(attempted),
            "failed": len(failed), "metrics": metrics, "device": device}

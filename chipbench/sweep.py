@@ -4,7 +4,12 @@ the benchmark's runs): one set-up, then a window at each rate.
 A rate's queue grows where, from the window's first half to its second,
 the median TTFT or the mean number of requests in the system (queued and
 served) rises by more than a quarter.  The knee is the highest rate swept
-below the first at which either does."""
+below the first at which either does.
+
+The turn spacing printed for a mix's ``lead_turn_s`` is, over the window's
+turns that their session follows with another, the turn's TTFT, its answer
+at the window's mean token gap, and the think time after it: a next turn
+seen to come due before the run ends would leave out the long answers."""
 from __future__ import annotations
 
 import json
@@ -38,8 +43,16 @@ def run(cell, seed: int, seconds: float, rates: list[float], devices, log) -> No
         pend_early = statistics.fmean(pend[:len(pend) // 2] or [0])
         pend_late = statistics.fmean(pend[len(pend) // 2:] or [0])
         by = {(x.session, x.turn): x for x in r.recs}
-        spacing = [by[(x.session, x.turn + 1)].due - x.due for x in att
-                   if (x.session, x.turn + 1) in by]
+        seen = [by[(x.session, x.turn + 1)].due - x.due for x in att
+                if (x.session, x.turn + 1) in by]
+        gap = statistics.fmean(stats.gaps(r.recs, r.w0, r.w1) or [0.0])
+        spacing = [t + (x.max_tokens - 1) * gap
+                   + p.sessions[x.session].think_s[x.turn]
+                   for x, t in zip(att, tt) if t != float("inf")
+                   and x.turn + 1 < len(p.sessions[x.session].turns)]
+        # requests in the system in each 10 s from the ramp's start
+        fill = [[n for t0, _, n in r.steps if r.t0 + a <= t0 < r.t0 + a + 10]
+                for a in range(0, int(r.w1 - r.t0), 10)]
         tt_early = stats.percentile(early, 50) if early else None
         tt_late = stats.percentile(late, 50) if late else None
         line = {
@@ -57,12 +70,17 @@ def run(cell, seed: int, seconds: float, rates: list[float], devices, log) -> No
             "pending_first_half": pend_early,
             "pending_second_half": pend_late,
             "turn_spacing_s": statistics.fmean(spacing) if spacing else None,
+            "turn_spacing_seen_s": statistics.fmean(seen) if seen else None,
+            "pending_by_10s": [round(statistics.fmean(f), 2) if f else None
+                               for f in fill],
             "grows": bool(tt_early is None or tt_late is None
                           or tt_late > 1.25 * tt_early
                           or pend_late > 1.25 * pend_early),
         }
         print(json.dumps(line), flush=True)
         log(harness.lateness(r.recs))
+        if i + 1 == len(rates):
+            break             # nothing follows to find the backlog
         t = time.perf_counter()
         while reg.pending() and time.perf_counter() - t < 120:
             reg.step()

@@ -35,6 +35,17 @@ def test_traced_run_reports_counter_metrics(name):
                         "ssd_scan_roofline"}
     assert names <= {m["name"] for m in smoke.cell(name).per_layer}
     assert "decode_step_ms" in names
+    assert {m["name"] for m in smoke.cell(name).per_layer
+            if m["source"] == "program_counter"} <= names
+
+
+def test_four_replicas_run_correct_at_smoke_size():
+    """A cell that names four replicas behind the cache-aware router: one
+    on each CPU host device, every replica checked."""
+    out = smoke.run("qwen2-sessions", trace=True, deploy=smoke.X4)
+    assert out["correct"], out
+    assert out["device"]["count"] == 4
+    assert "prefix_hit_share" in out["metrics"]
 
 
 def test_cli_refuses_the_cpu():
